@@ -19,6 +19,7 @@ sys.path.insert(0, _ROOT)
 import numpy as np
 
 from repro.api import MultiHDBSCAN
+from repro.core import multi
 
 
 def explore(n: int, kmax: int):
@@ -33,7 +34,7 @@ def explore(n: int, kmax: int):
 
     est = MultiHDBSCAN(kmax=kmax).fit(x)
     print(f"fitted n={len(x)} in "
-          f"{sum(v for k, v in est.timings_.items()):.2f}s "
+          f"{sum(est.timings_[k] for k in multi.FIT_STAGES):.2f}s "
           f"(mpts range [2, {kmax}] from ONE graph)\n")
 
     print(f"{'mpts':>5} {'clusters':>9} {'noise':>6} {'largest':>8} {'total_stab':>11}")

@@ -34,7 +34,7 @@ def main():
     print(f"\nMultiHDBSCAN: {len(profile)} hierarchies in {t_multi:.2f}s")
     print(f"  graph edges: {est.n_graph_edges_:,} "
           f"(complete graph: {len(x)*(len(x)-1)//2:,})")
-    print("  fit timings:", {k: round(v, 2) for k, v in est.timings_.items()})
+    print("  fit timings:", {k: round(est.timings_[k], 2) for k in multi.FIT_STAGES})
 
     t0 = time.monotonic()
     base, _ = multi.hdbscan_baseline(x, [kmax])

@@ -291,6 +291,7 @@ class FittedModel:
         )
 
     @staticmethod
+    @engine.trace.span("fit.provenance")
     def _fresh_provenance(plan: "engine.Plan", X: np.ndarray) -> dict:
         import jax
 

@@ -29,7 +29,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import time
 from typing import Sequence
 
 import jax
@@ -37,6 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import engine
+from ..engine import trace
 from . import hierarchy, linkage
 from . import boruvka
 from . import mrd as mrd_mod
@@ -113,6 +113,10 @@ class MultiDensityResult:
     timings: dict[str, float]
 
 
+# The spans that tile ``fit_msts``: their seconds add up to the whole fit.
+FIT_STAGES = ("knn", "rng_build", "mst_pad", "mst_range")
+
+
 @functools.partial(jax.jit, static_argnames=("n",))
 def _mst_stage_local(d2_pad, cd2_dev, ea, eb, row_idx, *, n: int):
     """Single-device MST stage as ONE program: reweight + batched Borůvka +
@@ -158,85 +162,93 @@ def fit_msts(
     plane), ``graph`` (inside build_rng_graph), and ``mst`` (the final MST
     compaction, the MST stage's single device->host sync; the row masks are
     compacted to (R, n-1) edge ids on device first).
+
+    ``timings`` is the fit's ``engine.trace`` recording: the seconds of each
+    span (``FIT_STAGES`` tile the fit; ``rng.*`` and ``to_host.<tag>`` nest
+    inside them), and ``compile`` / ``compiles`` for JAX's compiles.
     """
     plan = plan if isinstance(plan, engine.Plan) else engine.resolve_plan(plan, backend=backend)
     x_host = engine.io.ensure_host(x)
-    x = jnp.asarray(x_host)
-    n = x.shape[0]
+    n = x_host.shape[0]
     if kmax < 2 or kmax > n:
         raise ValueError(f"kmax must be in [2, n]; got {kmax} (n={n})")
     mpts_list = list(mpts_values) if mpts_values is not None else list(range(kmin, kmax + 1))
     if any(m < 1 or m > kmax for m in mpts_list):
         raise ValueError(f"mpts values must lie in [1, kmax]; got {mpts_list}")
-    timings: dict[str, float] = {}
 
-    t0 = time.monotonic()
-    knn_d2, knn_idx = plan.knn(x, kmax - 1, x_host=x_host)
-    cd2_dev = mrd_mod.core_distances2(knn_d2)
-    knn_host, knn_idx_host, cd2 = engine.to_host((knn_d2, knn_idx, cd2_dev), "knn")
-    timings["knn"] = time.monotonic() - t0
+    with trace.recording() as rec:
+        with trace.span("knn"):
+            x = jnp.asarray(x_host)
+            knn_d2, knn_idx = plan.knn(x, kmax - 1, x_host=x_host)
+            cd2_dev = mrd_mod.core_distances2(knn_d2)
+            knn_host, knn_idx_host, cd2 = engine.to_host((knn_d2, knn_idx, cd2_dev), "knn")
 
-    t0 = time.monotonic()
-    graph = build_rng_graph(
-        x,
-        knn_d2,
-        knn_idx,
-        variant=variant,
-        plan=plan,
-        x_host=x_host,
-        cd_kmax_host=np.sqrt(cd2[:, -1].astype(np.float64)),
-        knn_d2_host=knn_host,
-        knn_idx_host=knn_idx_host,
-    )
-    timings["rng_build"] = time.monotonic() - t0
+        with trace.span("rng_build"):
+            graph = build_rng_graph(
+                x,
+                knn_d2,
+                knn_idx,
+                variant=variant,
+                plan=plan,
+                x_host=x_host,
+                cd_kmax_host=np.sqrt(cd2[:, -1].astype(np.float64)),
+                knn_d2_host=knn_host,
+                knn_idx_host=knn_idx_host,
+            )
 
-    # quantize the edge count so the Borůvka/reweight programs compile one
-    # shape per scale bucket instead of one per dataset; padded edges are
-    # (0, 0) with +inf weight — same component, never cross, never chosen
-    m_real = len(graph.edges)
-    m_pad = max(4096, -(-m_real // 4096) * 4096)
-    ea = jnp.zeros((m_pad,), jnp.int32).at[:m_real].set(
-        jnp.asarray(graph.edges[:, 0], jnp.int32)
-    )
-    eb = jnp.zeros((m_pad,), jnp.int32).at[:m_real].set(
-        jnp.asarray(graph.edges[:, 1], jnp.int32)
-    )
-    d2_pad = jnp.full((m_pad,), jnp.inf, jnp.float32).at[:m_real].set(
-        jnp.asarray(graph.d2)
-    )
+        # quantize the edge count so the Borůvka/reweight programs compile one
+        # shape per scale bucket instead of one per dataset; padded edges are
+        # (0, 0) with +inf weight — same component, never cross, never chosen
+        with trace.span("mst_pad"):
+            m_real = len(graph.edges)
+            m_pad = max(4096, -(-m_real // 4096) * 4096)
+            ea = jnp.zeros((m_pad,), jnp.int32).at[:m_real].set(
+                jnp.asarray(graph.edges[:, 0], jnp.int32)
+            )
+            eb = jnp.zeros((m_pad,), jnp.int32).at[:m_real].set(
+                jnp.asarray(graph.edges[:, 1], jnp.int32)
+            )
+            d2_pad = jnp.full((m_pad,), jnp.inf, jnp.float32).at[:m_real].set(
+                jnp.asarray(graph.d2)
+            )
 
-    t0 = time.monotonic()
-    row_idx = jnp.asarray([m - 1 for m in mpts_list])
-    if plan.sharded:
-        w_range = mrd_mod.reweight_all_mpts(d2_pad, cd2_dev, ea, eb)
-        w_sel = w_range[row_idx]
-        in_mst = plan.mst_range(ea, eb, w_sel, n=n)
-        mst_dev = _compact_mst_rows(in_mst, ea, eb, w_sel, n=n)
-    else:
-        # single device: reweight + Borůvka + row compaction fused into one
-        # program (each row's mask compacts to (n-1) ascending edge ids via
-        # cumsum-positioned scatters), ending at the stage's one host sync
-        mst_dev = _mst_stage_local(d2_pad, cd2_dev, ea, eb, row_idx, n=n)
-    mst_ea, mst_eb, mst_w, counts = engine.to_host(mst_dev, "mst")
-    if not np.all(counts == n - 1):
-        # Borůvka exits via progressed=False on a disconnected edge list and
-        # returns < n-1 edges per row; consuming those rows downstream would
-        # feed garbage into linkage.  The RNG^kmax provably contains every
-        # per-mpts MST (paper Cor. 1), so disconnection here always means an
-        # upstream candidate/filter bug (or a hand-fed broken edge list) —
-        # fail loudly instead.
-        bad = {
-            mpts_list[i]: int(counts[i])
-            for i in np.flatnonzero(counts != n - 1)
-        }
-        raise RuntimeError(
-            f"MST incomplete: graph variant {variant!r} with "
-            f"{m_real} edges is disconnected — got "
-            f"{{mpts: n_tree_edges}} = {bad}, need {n - 1} edges per mpts. "
-            f"The RNG^kmax must contain every MST, so this indicates an "
-            f"upstream candidate-generation or filter bug."
-        )
-    timings["mst_range"] = time.monotonic() - t0
+        with trace.span("mst_range"):
+            row_idx = jnp.asarray([m - 1 for m in mpts_list])
+            if plan.sharded:
+                w_range = mrd_mod.reweight_all_mpts(d2_pad, cd2_dev, ea, eb)
+                w_sel = w_range[row_idx]
+                in_mst = plan.mst_range(ea, eb, w_sel, n=n)
+                mst_dev = _compact_mst_rows(in_mst, ea, eb, w_sel, n=n)
+            else:
+                # single device: reweight + Borůvka + row compaction fused into one
+                # program (each row's mask compacts to (n-1) ascending edge ids via
+                # cumsum-positioned scatters), ending at the stage's one host sync
+                mst_dev = _mst_stage_local(d2_pad, cd2_dev, ea, eb, row_idx, n=n)
+            mst_ea, mst_eb, mst_w, counts = engine.to_host(mst_dev, "mst")
+            if not np.all(counts == n - 1):
+                # Borůvka exits via progressed=False on a disconnected edge list and
+                # returns < n-1 edges per row; consuming those rows downstream would
+                # feed garbage into linkage.  The RNG^kmax provably contains every
+                # per-mpts MST (paper Cor. 1), so disconnection here always means an
+                # upstream candidate/filter bug (or a hand-fed broken edge list) —
+                # fail loudly instead.
+                bad = {
+                    mpts_list[i]: int(counts[i])
+                    for i in np.flatnonzero(counts != n - 1)
+                }
+                raise RuntimeError(
+                    f"MST incomplete: graph variant {variant!r} with "
+                    f"{m_real} edges is disconnected — got "
+                    f"{{mpts: n_tree_edges}} = {bad}, need {n - 1} edges per mpts. "
+                    f"The RNG^kmax must contain every MST, so this indicates an "
+                    f"upstream candidate-generation or filter bug."
+                )
+    counters = rec.counters()
+    timings = {
+        **rec.seconds(),
+        "compile": counters["compile"],
+        "compiles": float(counters["compiles"]),
+    }
 
     return MultiMSTResult(
         n=n,
@@ -253,6 +265,7 @@ def fit_msts(
     )
 
 
+@trace.span("extract.linkage")
 def linkage_range(msts: MultiMSTResult) -> LinkageRange:
     """All of the range's dendrograms in one batched device program.
 
@@ -344,6 +357,7 @@ def unpack_msts(arrays: dict[str, np.ndarray], meta: dict) -> MultiMSTResult:
     )
 
 
+@trace.span("extract.condense")
 def extract_one_from_linkage(
     msts: MultiMSTResult,
     lk: LinkageRange,
@@ -405,28 +419,32 @@ def extract_hierarchies(
     cluster_selection_epsilon: float = 0.0,
     policy=None,
 ) -> tuple[list[HierarchyResult], dict[str, float]]:
-    """Batched extraction of the whole range; returns (hierarchies, timings)."""
-    timings: dict[str, float] = {}
-    t0 = time.monotonic()
-    if lk is None:
-        lk = linkage_range(msts)
-    timings["hierarchy_linkage"] = time.monotonic() - t0
+    """Batched extraction of the whole range; returns (hierarchies, timings).
 
-    t0 = time.monotonic()
-    out = [
-        extract_one_from_linkage(
-            msts,
-            lk,
-            row,
-            min_cluster_size=min_cluster_size,
-            allow_single_cluster=allow_single_cluster,
-            cluster_selection_method=cluster_selection_method,
-            cluster_selection_epsilon=cluster_selection_epsilon,
-            policy=policy,
-        )
-        for row in range(len(msts.mpts_values))
-    ]
-    timings["hierarchy_condense"] = time.monotonic() - t0
+    ``hierarchy_linkage`` and ``hierarchy_condense`` are the ``extract.*``
+    spans' seconds (``engine.trace``); a given ``lk`` costs no linkage.
+    """
+    with trace.recording() as rec:
+        if lk is None:
+            lk = linkage_range(msts)
+        out = [
+            extract_one_from_linkage(
+                msts,
+                lk,
+                row,
+                min_cluster_size=min_cluster_size,
+                allow_single_cluster=allow_single_cluster,
+                cluster_selection_method=cluster_selection_method,
+                cluster_selection_epsilon=cluster_selection_epsilon,
+                policy=policy,
+            )
+            for row in range(len(msts.mpts_values))
+        ]
+    seconds = rec.seconds()
+    timings = {
+        "hierarchy_linkage": seconds.get("extract.linkage", 0.0),
+        "hierarchy_condense": seconds.get("extract.condense", 0.0),
+    }
     timings["hierarchy"] = timings["hierarchy_linkage"] + timings["hierarchy_condense"]
     return out, timings
 
@@ -465,9 +483,7 @@ def multi_hdbscan(
         timings.update(t_extract)
     else:
         timings["hierarchy"] = 0.0
-    timings["total"] = (
-        timings["knn"] + timings["rng_build"] + timings["mst_range"] + timings["hierarchy"]
-    )
+    timings["total"] = sum(timings[k] for k in FIT_STAGES) + timings["hierarchy"]
 
     return MultiDensityResult(
         n=msts.n,
@@ -503,52 +519,51 @@ def hdbscan_baseline(
     n = x.shape[0]
     mpts_list = list(mpts_values)
     kmax = kmax or max(mpts_list)
-    timings: dict[str, float] = {}
 
-    t0 = time.monotonic()
-    knn_d2, _ = plan.knn(x, kmax - 1, x_host=x_host)
-    cd2 = mrd_mod.core_distances2(knn_d2)
-    cd2.block_until_ready()
-    timings["knn"] = time.monotonic() - t0
-
-    t_mst = 0.0
     eb = np.arange(1, n, dtype=np.int32)
     mst_ea = np.zeros((len(mpts_list), n - 1), np.int32)
     mst_w = np.zeros((len(mpts_list), n - 1), np.float32)
-    for row, mpts in enumerate(mpts_list):
-        t0 = time.monotonic()
-        src, w2 = boruvka.prim_dense_mst(x, cd2[:, mpts - 1])
-        w2.block_until_ready()
-        t_mst += time.monotonic() - t0
-        src_h, w2_h = engine.to_host((src, w2), "mst")
-        mst_ea[row] = src_h[1:]
-        mst_w[row] = np.sqrt(w2_h[1:])
-    timings["mst"] = t_mst
-
     results: list[HierarchyResult] = []
-    t0 = time.monotonic()
-    if compute_hierarchies:
-        knn_d2_h, cd2_h = engine.to_host((knn_d2, cd2), "knn")
-        msts = MultiMSTResult(
-            n=n,
-            kmax=kmax,
-            mpts_values=mpts_list,
-            graph=None,
-            knn_d2=knn_d2_h,
-            knn_idx=np.zeros((n, 0), np.int32),
-            cd2=cd2_h,
-            mst_ea=mst_ea,
-            mst_eb=np.broadcast_to(eb, mst_ea.shape),
-            mst_w=mst_w,
-            timings={},
-        )
-        results, _ = extract_hierarchies(
-            msts,
-            min_cluster_size=min_cluster_size,
-            allow_single_cluster=allow_single_cluster,
-            cluster_selection_method=cluster_selection_method,
-            cluster_selection_epsilon=cluster_selection_epsilon,
-        )
-    timings["hierarchy"] = time.monotonic() - t0
-    timings["total"] = timings["knn"] + t_mst + timings["hierarchy"]
+    with trace.recording() as rec:
+        with trace.span("knn"):
+            knn_d2, _ = plan.knn(x, kmax - 1, x_host=x_host)
+            cd2 = mrd_mod.core_distances2(knn_d2)
+            cd2.block_until_ready()
+        for row, mpts in enumerate(mpts_list):
+            with trace.span("baseline.mst"):
+                src, w2 = boruvka.prim_dense_mst(x, cd2[:, mpts - 1])
+                w2.block_until_ready()
+            src_h, w2_h = engine.to_host((src, w2), "mst")
+            mst_ea[row] = src_h[1:]
+            mst_w[row] = np.sqrt(w2_h[1:])
+        with trace.span("baseline.extract"):
+            if compute_hierarchies:
+                knn_d2_h, cd2_h = engine.to_host((knn_d2, cd2), "knn")
+                msts = MultiMSTResult(
+                    n=n,
+                    kmax=kmax,
+                    mpts_values=mpts_list,
+                    graph=None,
+                    knn_d2=knn_d2_h,
+                    knn_idx=np.zeros((n, 0), np.int32),
+                    cd2=cd2_h,
+                    mst_ea=mst_ea,
+                    mst_eb=np.broadcast_to(eb, mst_ea.shape),
+                    mst_w=mst_w,
+                    timings={},
+                )
+                results, _ = extract_hierarchies(
+                    msts,
+                    min_cluster_size=min_cluster_size,
+                    allow_single_cluster=allow_single_cluster,
+                    cluster_selection_method=cluster_selection_method,
+                    cluster_selection_epsilon=cluster_selection_epsilon,
+                )
+    seconds = rec.seconds()
+    timings = {
+        "knn": seconds["knn"],
+        "mst": seconds.get("baseline.mst", 0.0),
+        "hierarchy": seconds["baseline.extract"],
+    }
+    timings["total"] = timings["knn"] + timings["mst"] + timings["hierarchy"]
     return results, timings

@@ -32,6 +32,11 @@ everything else is device-resident jax programs over padded/masked arrays.
 Host syncs are the named ledger points only: ``candidate_count`` /
 ``stage1_count`` (scalars sizing the static compactions), ``graph`` (the
 one bulk materialization), and ``lune_exact`` for the exact variant.
+Each host stage is an ``engine.trace`` span: ``rng.wspd`` (tree, pairs
+and their point ranges), ``rng.emit`` (candidate emission),
+``rng.cascade`` (the filter stages' dispatch), ``rng.graph_host`` (edge
+assembly after the ``graph`` sync) and, on the large-n tier,
+``rng.dualtree``.
 """
 
 from __future__ import annotations
@@ -44,6 +49,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import engine
+from ..engine import trace
 from . import dualtree as dualtree_mod
 from . import mrd as mrd_mod
 from . import sbcn as sbcn_mod
@@ -273,25 +279,28 @@ def _compact_idx(mask, *, cap: int):
 
 
 def _build_fused(
-    x, cd2, knn_d2, knn_idx, tree, pu, pv, variant, plan
+    x, cd2, knn_d2, knn_idx, perm, pairs, variant, plan
 ) -> RngGraph | None:
     """Fused-cascade RNG build; returns None on tie overflow (caller falls
-    back to the slot-array path, which keeps ALL tied SBCN minima)."""
+    back to the slot-array path, which keeps ALL tied SBCN minima).
+
+    ``pairs`` is the WSPD pair list as (a_start, a_len, b_start, b_len)
+    ranges of the fair-split tree's ``perm``."""
     n = x.shape[0]
+    n_pairs = len(pairs[0])
     cd2k = cd2[:, -1]
-    keys_sorted, n_real_d, n_unique_d, n_mutual_d, n_overflow_d = (
-        sbcn_mod.cascade_candidates(
-            x,
-            cd2k,
-            tree.perm,
-            tree.start[pu],
-            tree.end[pu] - tree.start[pu],
-            tree.start[pv],
-            tree.end[pv] - tree.start[pv],
-            tie_cap=plan.cascade_tie_cap,
-            tier_chunk_elems=plan.tier_chunk_elems,
+    with trace.recording() as emitted:
+        keys_sorted, n_real_d, n_unique_d, n_mutual_d, n_overflow_d = (
+            sbcn_mod.cascade_candidates(
+                x,
+                cd2k,
+                perm,
+                *pairs,
+                tie_cap=plan.cascade_tie_cap,
+                tier_chunk_elems=plan.tier_chunk_elems,
+            )
         )
-    )
+    emit_counts = emitted.counters()
     # ONE scalar sync sizes the stage-1 buffer and reports the exact tie
     # overflow verdict (no silent edge drops — overflow means fall back)
     n_real, n_unique, n_mutual, n_overflow = (
@@ -303,7 +312,7 @@ def _build_fused(
     if n_overflow:
         return None
     if n_real == 0:
-        return _empty_graph(variant, n, int(len(pu)))
+        return _empty_graph(variant, n, n_pairs)
 
     cap = min(_pow2_ceil(n_real), keys_sorted.shape[0])
     ks = keys_sorted[:cap]
@@ -311,105 +320,112 @@ def _build_fused(
 
     stats = {
         "m_candidates": n_unique,
-        "n_wspd_pairs": int(len(pu)),
+        "n_wspd_pairs": n_pairs,
         "m_candidate_slots": n_real,
         "m_mutual_slots": n_mutual,
+        "emit_dispatches": int(
+            emit_counts.get("emit.tier_dispatches", 0)
+            + emit_counts.get("emit.rowpath_dispatches", 0)
+        ),
+        "emit_upload_bytes": int(emit_counts.get("emit.upload_bytes", 0)),
         "path": "fused",
     }
 
-    # stage 1: cheap prefilter over each endpoint's k1 nearest — its
-    # removals are a subset of the full check's, so survivors-only stage 2
-    # gives the identical final verdict for a fraction of the work.  The
-    # core-distance certificate splits the survivors further: a certified
-    # edge has w == max(cd(a), cd(b)) and every lune competitor c satisfies
-    # mrd(a,c) >= cd(a) and mrd(b,c) >= cd(b) (max is exact in f32), so
-    # nothing can ever lie strictly inside its lune — certified survivors
-    # skip the full check entirely.
-    k_full = knn_idx.shape[1]
-    k1 = min(plan.cascade_stage1_k, k_full)
-    if plan.backend in ("pallas", "pallas_interpret"):
-        valid, first, lo, hi = _unpack_keys(ks, n_pack)
-        killed1, cert1, d2_1, w2_1 = plan.edge_cascade(
-            x, cd2k, knn_idx, knn_d2, lo, hi, valid, k_check=k1
-        )
-        surv_cert, surv_open, nc_d, no_d = _split_survivors(
-            valid, first, killed1, cert1
-        )
-    else:
-        # jnp backends: the whole stage-1 block is one program
-        from ..kernels import fused_cascade as fc
+    with trace.span("rng.cascade"):
+        # stage 1: cheap prefilter over each endpoint's k1 nearest — its
+        # removals are a subset of the full check's, so survivors-only stage 2
+        # gives the identical final verdict for a fraction of the work.  The
+        # core-distance certificate splits the survivors further: a certified
+        # edge has w == max(cd(a), cd(b)) and every lune competitor c satisfies
+        # mrd(a,c) >= cd(a) and mrd(b,c) >= cd(b) (max is exact in f32), so
+        # nothing can ever lie strictly inside its lune — certified survivors
+        # skip the full check entirely.
+        k_full = knn_idx.shape[1]
+        k1 = min(plan.cascade_stage1_k, k_full)
+        if plan.backend in ("pallas", "pallas_interpret"):
+            valid, first, lo, hi = _unpack_keys(ks, n_pack)
+            killed1, cert1, d2_1, w2_1 = plan.edge_cascade(
+                x, cd2k, knn_idx, knn_d2, lo, hi, valid, k_check=k1
+            )
+            surv_cert, surv_open, nc_d, no_d = _split_survivors(
+                valid, first, killed1, cert1
+            )
+        else:
+            # jnp backends: the whole stage-1 block is one program
+            from ..kernels import fused_cascade as fc
 
-        lo, hi, d2_1, w2_1, surv_cert, surv_open, nc_d, no_d = fc.stage1_packed(
-            x, cd2k, knn_idx, knn_d2, ks, n_pack,
-            k_check=k1, chunk=plan.cascade_chunk,
+            lo, hi, d2_1, w2_1, surv_cert, surv_open, nc_d, no_d = fc.stage1_packed(
+                x, cd2k, knn_idx, knn_d2, ks, n_pack,
+                k_check=k1, chunk=plan.cascade_chunk,
+            )
+        n_cert, n_open = (
+            int(v) for v in engine.to_host((nc_d, no_d), "stage1_count")
         )
-    n_cert, n_open = (
-        int(v) for v in engine.to_host((nc_d, no_d), "stage1_count")
-    )
-    if n_cert + n_open == 0:
-        return _empty_graph(variant, n, int(len(pu)))
+        if n_cert + n_open == 0:
+            return _empty_graph(variant, n, n_pairs)
 
-    q = 8192  # survivor caps quantized to coarse blocks: few programs, <12% pad
-    parts_dev = []
-    if n_cert:
-        capc = min(_pow2_ceil(n_cert), -(-n_cert // q) * q)
-        posc = _compact_idx(surv_cert, cap=capc)
-        validc = jnp.arange(capc) < n_cert
-        keepc = validc  # certified => provably in the exact RNG
-        d2c, w2c = canonical_edge_weights(x, cd2k, lo[posc], hi[posc])
-        parts_dev.append(
-            (lo[posc], hi[posc], keepc, validc, d2c, w2c, w2_1[posc])
-        )
-    if n_open:
-        capo = min(_pow2_ceil(n_open), -(-n_open // q) * q)
-        poso = _compact_idx(surv_open, cap=capo)
-        valido = jnp.arange(capo) < n_open
-        killed2, _, d2_2, w2_2 = plan.edge_cascade(
-            x, cd2k, knn_idx, knn_d2, lo[poso], hi[poso], valido,
-            k_check=k_full,
-        )
-        d2o, w2o = canonical_edge_weights(x, cd2k, lo[poso], hi[poso])
-        parts_dev.append(
-            (lo[poso], hi[poso], valido & ~killed2, jnp.zeros_like(valido),
-             d2o, w2o, w2_2)
-        )
+        q = 8192  # survivor caps quantized to coarse blocks: few programs, <12% pad
+        parts_dev = []
+        if n_cert:
+            capc = min(_pow2_ceil(n_cert), -(-n_cert // q) * q)
+            posc = _compact_idx(surv_cert, cap=capc)
+            validc = jnp.arange(capc) < n_cert
+            keepc = validc  # certified => provably in the exact RNG
+            d2c, w2c = canonical_edge_weights(x, cd2k, lo[posc], hi[posc])
+            parts_dev.append(
+                (lo[posc], hi[posc], keepc, validc, d2c, w2c, w2_1[posc])
+            )
+        if n_open:
+            capo = min(_pow2_ceil(n_open), -(-n_open // q) * q)
+            poso = _compact_idx(surv_open, cap=capo)
+            valido = jnp.arange(capo) < n_open
+            killed2, _, d2_2, w2_2 = plan.edge_cascade(
+                x, cd2k, knn_idx, knn_d2, lo[poso], hi[poso], valido,
+                k_check=k_full,
+            )
+            d2o, w2o = canonical_edge_weights(x, cd2k, lo[poso], hi[poso])
+            parts_dev.append(
+                (lo[poso], hi[poso], valido & ~killed2, jnp.zeros_like(valido),
+                 d2o, w2o, w2_2)
+            )
 
     parts = engine.to_host(parts_dev, "graph")
-    lo_h = np.concatenate([p[0] for p in parts])
-    hi_h = np.concatenate([p[1] for p in parts])
-    keep = np.concatenate([p[2] for p in parts])
-    certified = np.concatenate([p[3] for p in parts])
-    d2_h = np.concatenate([p[4] for p in parts])
-    w2_h = np.concatenate([p[5] for p in parts])
-    w2_stage = np.concatenate([p[6] for p in parts])
-    # restore the slot path's sorted-(lo, hi) edge order: downstream MST
-    # tie-breaks are by edge id, so order parity keeps the paths bit-equal
-    order = np.lexsort((hi_h, lo_h))
-    lo_h, hi_h, keep, certified, d2_h, w2_h, w2_stage = (
-        v[order] for v in (lo_h, hi_h, keep, certified, d2_h, w2_h, w2_stage)
-    )
-    stats["m_removed_knn"] = n_unique - int(keep.sum())
-    stats["m_certified"] = int((keep & certified).sum())
-
-    if variant == "rng":
-        # the lune pass thresholds on the STAGE w2 values (their verdicts
-        # carry the eps margins); the exported arrays stay canonical
-        keep = _exact_lune_pass(
-            keep, certified, lo_h, hi_h, w2_stage, x, cd2k, plan, stats
+    with trace.span("rng.graph_host"):
+        lo_h = np.concatenate([p[0] for p in parts])
+        hi_h = np.concatenate([p[1] for p in parts])
+        keep = np.concatenate([p[2] for p in parts])
+        certified = np.concatenate([p[3] for p in parts])
+        d2_h = np.concatenate([p[4] for p in parts])
+        w2_h = np.concatenate([p[5] for p in parts])
+        w2_stage = np.concatenate([p[6] for p in parts])
+        # restore the slot path's sorted-(lo, hi) edge order: downstream MST
+        # tie-breaks are by edge id, so order parity keeps the paths bit-equal
+        order = np.lexsort((hi_h, lo_h))
+        lo_h, hi_h, keep, certified, d2_h, w2_h, w2_stage = (
+            v[order] for v in (lo_h, hi_h, keep, certified, d2_h, w2_h, w2_stage)
         )
+        stats["m_removed_knn"] = n_unique - int(keep.sum())
+        stats["m_certified"] = int((keep & certified).sum())
 
-    edges = np.stack(
-        [lo_h[keep].astype(np.int64), hi_h[keep].astype(np.int64)], axis=1
-    )
-    stats["m_edges"] = int(len(edges))
-    return RngGraph(
-        edges=edges,
-        d2=d2_h[keep],
-        w2_kmax=w2_h[keep],
-        variant=variant,
-        n_points=n,
-        stats=stats,
-    )
+        if variant == "rng":
+            # the lune pass thresholds on the STAGE w2 values (their verdicts
+            # carry the eps margins); the exported arrays stay canonical
+            keep = _exact_lune_pass(
+                keep, certified, lo_h, hi_h, w2_stage, x, cd2k, plan, stats
+            )
+
+        edges = np.stack(
+            [lo_h[keep].astype(np.int64), hi_h[keep].astype(np.int64)], axis=1
+        )
+        stats["m_edges"] = int(len(edges))
+        return RngGraph(
+            edges=edges,
+            d2=d2_h[keep],
+            w2_kmax=w2_h[keep],
+            variant=variant,
+            n_points=n,
+            stats=stats,
+        )
 
 
 # ---------------------------------------------------------------------------
@@ -489,13 +505,14 @@ def _build_dualtree(
     NOT an RNG: the ``variant`` filter semantics don't apply on this tier.
     """
     n = x.shape[0]
-    edges, stats = dualtree_mod.candidate_edges(
-        x_host,
-        knn_d2_host,
-        knn_idx_host,
-        leaf_size=plan.dualtree_leaf,
-        margin=plan.dualtree_margin,
-    )
+    with trace.span("rng.dualtree"):
+        edges, stats = dualtree_mod.candidate_edges(
+            x_host,
+            knn_d2_host,
+            knn_idx_host,
+            leaf_size=plan.dualtree_leaf,
+            margin=plan.dualtree_margin,
+        )
     stats["path"] = "dualtree"
     m = len(edges)
     stats["m_edges"] = m
@@ -569,14 +586,18 @@ def build_rng_graph(
         )
 
     # -- host control plane: fair-split tree + well-separated pairs ---------
-    tree = wspd_mod.build_fair_split_tree(
-        np.asarray(x_host, np.float64), cd_kmax_host
-    )
-    pu, pv = wspd_mod.wspd_pairs(tree, s=separation)
+    with trace.span("rng.wspd"):
+        tree = wspd_mod.build_fair_split_tree(
+            np.asarray(x_host, np.float64), cd_kmax_host
+        )
+        pu, pv = wspd_mod.wspd_pairs(tree, s=separation)
+        a_start, b_start = tree.start[pu], tree.start[pv]
+        pairs = (a_start, tree.end[pu] - a_start, b_start, tree.end[pv] - b_start)
+    n_pairs = len(pu)
 
     # -- fused cascade (default): bounded emission + staged fused filter ----
     if variant != "rng_ss" and plan.backend != "ref" and n <= _PACK_LIMIT:
-        g = _build_fused(x, cd2, knn_d2, knn_idx, tree, pu, pv, variant, plan)
+        g = _build_fused(x, cd2, knn_d2, knn_idx, tree.perm, pairs, variant, plan)
         if g is not None:
             return g
         # per-row tie overflow (mass duplicates): the slot path below keeps
@@ -587,10 +608,7 @@ def build_rng_graph(
         x,
         cd2[:, -1],
         tree.perm,
-        tree.start[pu],
-        tree.end[pu] - tree.start[pu],
-        tree.start[pv],
-        tree.end[pv] - tree.start[pv],
+        *pairs,
         tile_elems=plan.sbcn_tile_elems,
         pair_cap=plan.sbcn_pair_cap,
         row_chunk=plan.sbcn_row_chunk,
@@ -607,28 +625,29 @@ def build_rng_graph(
             w2_kmax=np.zeros((0,), np.float32),
             variant=variant,
             n_points=n,
-            stats={"m_candidates": 0, "n_wspd_pairs": int(len(pu)), "m_edges": 0},
+            stats={"m_candidates": 0, "n_wspd_pairs": n_pairs, "m_edges": 0},
         )
-    cap = -(-m_cand // 4096) * 4096  # quantized: reuses filter programs
-    pos = jnp.nonzero(keep_s, size=cap, fill_value=0)[0]
-    lo = lo_s[pos]
-    hi = hi_s[pos]
-    valid = jnp.arange(cap) < m_cand
+    with trace.span("rng.cascade"):
+        cap = -(-m_cand // 4096) * 4096  # quantized: reuses filter programs
+        pos = jnp.nonzero(keep_s, size=cap, fill_value=0)[0]
+        lo = lo_s[pos]
+        hi = hi_s[pos]
+        valid = jnp.arange(cap) < m_cand
 
-    cd2k = cd2[:, -1]
-    ea = jnp.where(valid, lo, 0).astype(jnp.int32)
-    eb = jnp.where(valid, hi, 0).astype(jnp.int32)
-    if variant == "rng_ss":
-        keep_d = valid
-        certified_d = inside_d = jnp.zeros_like(valid)
-        w2_d = jnp.zeros((int(valid.shape[0]),), jnp.float32)
-    else:
-        keep_d, certified_d, inside_d, _, w2_d = filter_cascade_device(
-            x, cd2, knn_idx, knn_d2, lo, hi, valid, plan=plan
-        )
-    # exported weights always come from the canonical program (the filter
-    # verdicts above keep their own in-program values)
-    d2c_d, w2c_d = canonical_edge_weights(x, cd2k, ea, eb)
+        cd2k = cd2[:, -1]
+        ea = jnp.where(valid, lo, 0).astype(jnp.int32)
+        eb = jnp.where(valid, hi, 0).astype(jnp.int32)
+        if variant == "rng_ss":
+            keep_d = valid
+            certified_d = inside_d = jnp.zeros_like(valid)
+            w2_d = jnp.zeros((int(valid.shape[0]),), jnp.float32)
+        else:
+            keep_d, certified_d, inside_d, _, w2_d = filter_cascade_device(
+                x, cd2, knn_idx, knn_d2, lo, hi, valid, plan=plan
+            )
+        # exported weights always come from the canonical program (the filter
+        # verdicts above keep their own in-program values)
+        d2c_d, w2c_d = canonical_edge_weights(x, cd2k, ea, eb)
 
     # -- the one graph materialization --------------------------------------
     lo_h, hi_h, valid_h, keep, certified, inside_any, d2_h, w2_h, w2_stage = (
@@ -637,28 +656,29 @@ def build_rng_graph(
             "graph",
         )
     )
-    stats = {
-        "m_candidates": int(valid_h.sum()),
-        "n_wspd_pairs": int(len(pu)),
-    }
-    if variant != "rng_ss":
-        stats["m_removed_knn"] = int(inside_any.sum())
-        stats["m_certified"] = int((keep & certified).sum())
+    with trace.span("rng.graph_host"):
+        stats = {
+            "m_candidates": int(valid_h.sum()),
+            "n_wspd_pairs": n_pairs,
+        }
+        if variant != "rng_ss":
+            stats["m_removed_knn"] = int(inside_any.sum())
+            stats["m_certified"] = int((keep & certified).sum())
 
-    if variant == "rng":
-        keep = _exact_lune_pass(
-            keep, certified, lo_h, hi_h, w2_stage, x, cd2[:, -1], plan, stats
+        if variant == "rng":
+            keep = _exact_lune_pass(
+                keep, certified, lo_h, hi_h, w2_stage, x, cd2[:, -1], plan, stats
+            )
+
+        edges = np.stack(
+            [lo_h[keep].astype(np.int64), hi_h[keep].astype(np.int64)], axis=1
         )
-
-    edges = np.stack(
-        [lo_h[keep].astype(np.int64), hi_h[keep].astype(np.int64)], axis=1
-    )
-    stats["m_edges"] = int(len(edges))
-    return RngGraph(
-        edges=edges,
-        d2=d2_h[keep],
-        w2_kmax=w2_h[keep],
-        variant=variant,
-        n_points=n,
-        stats=stats,
-    )
+        stats["m_edges"] = int(len(edges))
+        return RngGraph(
+            edges=edges,
+            d2=d2_h[keep],
+            w2_kmax=w2_h[keep],
+            variant=variant,
+            n_points=n,
+            stats=stats,
+        )

@@ -29,6 +29,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..engine import trace
+
 _PAIR_ELEM_CAP = 1 << 18  # max padded |A|*|B| handled by the batched path
 _TILE_ELEMS = 1 << 22     # elements per tier-program chunk
 _ROW_CHUNK = 2048         # row chunk for oversized pairs
@@ -165,6 +167,7 @@ def _compact_slots(lo, hi, out_lo, out_hi):
     )
 
 
+@trace.span("rng.emit")
 def sbcn_candidates(
     x: jax.Array,
     cd2_kmax: jax.Array,
@@ -453,6 +456,7 @@ def _pow2_ceil_np(v: np.ndarray) -> np.ndarray:
     )
 
 
+@trace.span("rng.emit")
 def cascade_candidates(
     x: jax.Array,
     cd2_kmax: jax.Array,
@@ -477,6 +481,10 @@ def cascade_candidates(
 
     Requires n <= 46340 (packed ``lo * n + hi`` must fit int32); callers
     gate on that before choosing this path.
+
+    The whole call is the ``rng.emit`` span; each emission launch counts as
+    ``emit.tier_dispatches`` or ``emit.rowpath_dispatches``, and the host
+    blocks it uploads as ``emit.upload_bytes`` (``engine.trace``).
     """
     from .. import engine
 
@@ -500,7 +508,9 @@ def cascade_candidates(
             np.minimum(pa, pb).astype(np.int64) * int(x.shape[0])
             + np.maximum(pa, pb)
         )
-        key_parts.append(jnp.asarray(ss_keys.astype(np.int32)))
+        ss_keys = ss_keys.astype(np.int32)
+        trace.count("emit.upload_bytes", ss_keys.nbytes)
+        key_parts.append(jnp.asarray(ss_keys))
 
     rest = np.nonzero(~ss)[0]
     if len(rest):
@@ -523,11 +533,11 @@ def cascade_candidates(
                 lambda: functools.partial(_tier_emit, tie_cap=tie_cap),
             )
             for c0 in range(0, P_pad, chunk):
+                a_blk, b_blk = a_pad[c0 : c0 + chunk], b_pad[c0 : c0 + chunk]
+                trace.count("emit.tier_dispatches")
+                trace.count("emit.upload_bytes", a_blk.nbytes + b_blk.nbytes)
                 keys_c, counters_c = emit(
-                    x, cd2_kmax,
-                    jnp.asarray(a_pad[c0 : c0 + chunk]),
-                    jnp.asarray(b_pad[c0 : c0 + chunk]),
-                    n_pack,
+                    x, cd2_kmax, jnp.asarray(a_blk), jnp.asarray(b_blk), n_pack
                 )
                 key_parts.append(keys_c)
                 counter_parts.append(counters_c)
@@ -563,6 +573,8 @@ def cascade_candidates(
                         perm, a_start[grp], a_len[grp], ncc * rcc, Pb
                     ).reshape(Pb, ncc, rcc)
                     b_blk = _padded_gather(perm, b_start[grp], b_len[grp], nbb, Pb)
+                    trace.count("emit.rowpath_dispatches")
+                    trace.count("emit.upload_bytes", a_blk.nbytes + b_blk.nbytes)
                     keys_c, counters_c = emit(
                         x, cd2_kmax, jnp.asarray(a_blk), jnp.asarray(b_blk), n_pack
                     )

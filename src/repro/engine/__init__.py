@@ -11,14 +11,15 @@ so the same user code runs on a laptop (mesh ignored / trivial) and a pod.
 ``io`` holds the device->host choke point: every bulk materialization in the
 pipeline goes through ``to_host``, which a test ledger can count (and, under
 ``transfer_ledger``, a jax transfer guard turns any *implicit* device->host
-sync into an error).
+sync into an error).  ``trace`` holds the host spans and counters that name
+each stage's host time, on the profiler's clock.
 """
 
-from . import io, plan
+from . import io, plan, trace
 from .io import to_host, transfer_ledger
 from .plan import Plan, cached_program, resolve_plan
 
 __all__ = [
     "Plan", "cached_program", "io", "plan", "resolve_plan", "to_host",
-    "transfer_ledger",
+    "trace", "transfer_ledger",
 ]

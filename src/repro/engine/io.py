@@ -34,6 +34,9 @@ that bulk device->host syncs happen ONLY at named materialization points:
                         loop is inherently host-driven; out of the clustering
                         pipeline's budget).
 
+Each ``to_host`` call is also the span ``to_host.<tag>`` (``engine.trace``):
+the host waiting on the device and copying, on the profiler's clock.
+
 Everything else stays device-resident.  ``transfer_ledger`` is the test hook
 that enforces this: inside the context every ``to_host`` call is recorded as
 ``(tag, nbytes)`` and jax's transfer guard turns any *implicit* device->host
@@ -47,6 +50,8 @@ import threading
 
 import jax
 import numpy as np
+
+from . import trace
 
 _LEDGER = threading.local()
 
@@ -63,7 +68,8 @@ def to_host(tree, tag: str):
     This is the ONLY sanctioned device->host transfer in the clustering
     pipeline; ``tag`` names the materialization point (see module docstring).
     """
-    out = jax.device_get(tree)
+    with trace.span(trace.SYNC_PREFIX + tag):
+        out = jax.device_get(tree)
     ledger = getattr(_LEDGER, "value", None)
     if ledger is not None:
         ledger.append((tag, _nbytes(out)))
