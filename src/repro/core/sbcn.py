@@ -305,7 +305,7 @@ def sbcn_candidates(
 _SMALL_AMAX = 4          # bucketed-tier path bounds (pow2-exact tiers)
 _SMALL_BMAX = 8
 _TIER_CHUNK_ELEMS = 1 << 17   # fixed cells per tier chunk => shape-stable programs
-_ROWPATH_PAIR_BLOCK = 32      # pairs per row-path dispatch (fixed)
+_ROWPATH_CELLS = 1 << 21      # padded cells per row-path launch (sizes its pair block)
 
 # Compile contracts for the two emission families (enforced by
 # engine.plan.cached_program at registration, re-derived statically by
@@ -313,8 +313,9 @@ _ROWPATH_PAIR_BLOCK = 32      # pairs per row-path dispatch (fixed)
 # pow2-exact (kaa <= kbb) grid under (_SMALL_AMAX, _SMALL_BMAX) minus the
 # host-emitted (1, 1) singleton pair: 8 shapes; chunk is a pure function of
 # (kaa, kbb).  The row path walks (rc, nc, nb) pow2 ladders capped by the
-# packed-key n <= 46340 gate; Pb is derived.  See tools/audit/registry.py
-# for the exact enumerations these ceilings are checked against.
+# packed-key n <= 46340 gate; Pb is derived (``_rowpath_block``).  See
+# tools/audit/registry.py for the exact enumerations these ceilings are
+# checked against.
 from .. import engine as _engine  # noqa: E402  (constants above feed the declarations)
 
 _engine.plan.declare_family(
@@ -380,15 +381,29 @@ def _tier_emit(x, cd2k, a_idx, b_idx, n_pack, *, tie_cap: int):
     return _emit_from_mask(mutual, a_idx, b_idx, n_pack, tie_cap)
 
 
+def _rowpath_block(rc: int, nc: int, nb: int) -> int:
+    """Pairs per row-path launch: as many as ``_ROWPATH_CELLS`` padded cells
+    hold, at least 2.  A pure function of the shape key, so each shape is
+    one program whatever the number of pairs."""
+    return max(2, _ROWPATH_CELLS // (nc * rc * nb))
+
+
 @functools.partial(jax.jit, static_argnames=("tie_cap",))
 def _rowpath_emit(x, cd2k, a_chunks, b_idx, n_pack, *, tie_cap: int):
-    """Row-chunked SBCN emission for a block of same-shape oversized pairs.
+    """SBCN emission for a block of same-shape pairs too large for a tier.
 
-    a_chunks (Pb, nc, rc) int32 padded -1; b_idx (Pb, nb) padded -1.  Same
-    two-pass min-reduction as ``_sbcn_large`` (bit-identical mrd tiles and
-    tie tolerance), but emits bounded per-row keys instead of the dense
-    (na, nb) mask.  Peak memory is O(rc * nb) per pair regardless of na.
+    a_chunks (Pb, nc, rc) int32 padded -1; b_idx (Pb, nb) padded -1.  With
+    one row chunk (nc == 1) the block is ONE batched (Pb, rc, nb) mrd tile,
+    the tier path's ``_mutual_mask``.  With more, each pair in turn runs the
+    two-pass row-chunked min-reduction of ``_sbcn_large`` (bit-identical mrd
+    tiles and tie tolerance), so peak memory is O(rc * nb) per pair
+    regardless of na.  Both emit bounded per-row keys, not the dense mask.
     """
+    if a_chunks.shape[1] == 1:
+        a_idx = a_chunks[:, 0]
+        mutual = _mutual_mask(x, cd2k, a_idx, b_idx)
+        return _emit_from_mask(mutual, a_idx, b_idx, n_pack, tie_cap)
+
     eps = jnp.float32(_EPS)
 
     def one_pair(args):
@@ -409,16 +424,6 @@ def _rowpath_emit(x, cd2k, a_chunks, b_idx, n_pack, *, tie_cap: int):
             tol = eps * (anorm[:, None] + bnorm[None, :])
             return m, tol
 
-        def emit(m, tol, col_min, ac):
-            row_min = jnp.min(m, axis=1, keepdims=True)
-            mask = (m <= row_min + tol) & (m <= col_min + tol) & jnp.isfinite(m)
-            return _emit_from_mask(mask[None], ac[None], bj[None], n_pack, tie_cap)
-
-        if ac_all.shape[0] == 1:
-            # single row chunk: the tile IS the whole pair — one pass
-            m, tol = mrd_chunk(ac_all[0])
-            return emit(m, tol, jnp.min(m, axis=0, keepdims=True), ac_all[0])
-
         def pass1(ac):
             return jnp.min(mrd_chunk(ac)[0], axis=0)
 
@@ -426,7 +431,9 @@ def _rowpath_emit(x, cd2k, a_chunks, b_idx, n_pack, *, tie_cap: int):
 
         def pass2(ac):
             m, tol = mrd_chunk(ac)
-            return emit(m, tol, col_min, ac)
+            row_min = jnp.min(m, axis=1, keepdims=True)
+            mask = (m <= row_min + tol) & (m <= col_min + tol) & jnp.isfinite(m)
+            return _emit_from_mask(mask[None], ac[None], bj[None], n_pack, tie_cap)
 
         keys, counters = jax.lax.map(pass2, ac_all)
         return keys.reshape(-1), jnp.sum(counters, axis=0, dtype=jnp.int32)
@@ -557,12 +564,9 @@ def cascade_candidates(
                 rcc = int(rc[shape_key == skey][0])
                 ncc = int(nc[shape_key == skey][0])
                 nbb = int(nbp[shape_key == skey][0])
-                # pair block bounded by a cell budget: huge tiles dispatch in
-                # small blocks so a lone oversized pair never pays for a full
-                # block of padding
-                Pb = int(
-                    min(_ROWPATH_PAIR_BLOCK, max(2, (1 << 21) // (ncc * rcc * nbb)))
-                )
+                # a launch holds as many pairs as the cell budget allows:
+                # up to 1,024 of the smallest tiles, 2 of the largest
+                Pb = _rowpath_block(rcc, ncc, nbb)
                 emit = engine.plan.cached_program(
                     ("rowpath_emit", rcc, ncc, nbb, Pb, tie_cap, x.shape[1]),
                     lambda: functools.partial(_rowpath_emit, tie_cap=tie_cap),

@@ -143,8 +143,10 @@ def _tier_emit():
 def _rowpath_emit():
     from repro.core import sbcn
 
-    rc, nc, nb = 32, 1, 64   # smallest row-path bucket (the n=4000 workhorse)
-    pb = int(min(sbcn._ROWPATH_PAIR_BLOCK, max(2, (1 << 21) // (nc * rc * nb))))
+    # smallest row-chunked bucket: with nc == 1 the program is the tier
+    # body (_mutual_mask + _emit_from_mask), which the tier_emit spec traces
+    rc, nc, nb = 256, 2, 512
+    pb = sbcn._rowpath_block(rc, nc, nb)
     fn = functools.partial(sbcn._rowpath_emit, tie_cap=3)
     return fn, (_f32(N, D), _f32(N), _i32(pb, nc, rc), _i32(pb, nb), _i32())
 

@@ -82,10 +82,7 @@ def enumerate_rowpath_emit() -> set[tuple]:
         for nbp, max_nb in b_classes.items():
             if min_na > max_nb:
                 continue  # unreachable: canonicalization guarantees al <= bl
-            pb = int(min(
-                sbcn._ROWPATH_PAIR_BLOCK, max(2, (1 << 21) // (nc * rc * nbp))
-            ))
-            out.add((rc, nc, nbp, pb))
+            out.add((rc, nc, nbp, sbcn._rowpath_block(rc, nc, nbp)))
     return out
 
 
